@@ -11,6 +11,8 @@ On shared CPU runners the devices are threads over a few cores, so the
 interesting signal is "does sharding beat the sequential chunk loop at
 all" (>1x), not linear scaling — real meshes (one accelerator per
 device, multi-host) are where the slab-per-device dispatch pays off.
+This bench is a CPU-only rehearsal: its children are pinned to
+JAX_PLATFORMS=cpu.  The sharded sweep on chips is `chip_smoke.py --chips 4`.
 """
 
 from __future__ import annotations

@@ -29,6 +29,7 @@ import numpy as np
 from repro.core import calibration as cal
 from repro.core import dse
 from repro.core.space import DesignSpace
+from repro.runtime.compile_cache import enable_compile_cache
 
 parser = argparse.ArgumentParser()
 parser.add_argument("--smoke", action="store_true",
@@ -55,6 +56,7 @@ parser.add_argument("--replica", action="store_true",
                          "fires on a per-point replica column's crossing "
                          "instead of the fixed own-90%% window")
 args = parser.parse_args()
+enable_compile_cache()
 
 sharding = None
 if args.sharded:

@@ -71,6 +71,22 @@ MC_SAMPLED_FIELDS = ("margin_mv", "margin_disturbed_mv",
                      "trc_ns", "t_sense_ns", "t_fire_ns", "margin_fire_mv")
 
 
+def batches_identical(a, b) -> bool:
+    """NaN-aware bit-identity over every array field + corner channel."""
+
+    def eq(x, y):
+        x, y = np.asarray(x), np.asarray(y)
+        if x.shape != y.shape or x.dtype != y.dtype:
+            return False
+        if x.dtype.kind == "f":
+            return bool(((x == y) | (np.isnan(x) & np.isnan(y))).all())
+        return bool((x == y).all())
+
+    return (set(a.corners) == set(b.corners)
+            and all(eq(getattr(a, f), getattr(b, f)) for f in ARRAY_FIELDS)
+            and all(eq(a.corners[k], b.corners[k]) for k in a.corners))
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclass(frozen=True)
 class DesignBatch:

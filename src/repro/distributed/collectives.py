@@ -19,7 +19,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -96,10 +95,10 @@ def hierarchical_psum_tree(grads, mesh: Mesh, compress: bool = False,
 
     leaves, treedef = jax.tree.flatten(grads)
     spec = P()  # every leaf fully replicated; shard_map sees local copies
-    fn = shard_map(inner, mesh=mesh,
-                   in_specs=tuple(spec for _ in leaves),
-                   out_specs=tuple(spec for _ in range(2 * len(leaves))),
-                   check_rep=False)
+    fn = jax.shard_map(inner, mesh=mesh,
+                       in_specs=tuple(spec for _ in leaves),
+                       out_specs=tuple(spec for _ in range(2 * len(leaves))),
+                       check_vma=False)
     results = fn(*leaves)
     outs = jax.tree.unflatten(treedef, results[: len(leaves)])
     errs = jax.tree.unflatten(treedef, results[len(leaves):])

@@ -1,9 +1,11 @@
 """Jit'd public wrappers for the Pallas kernels, with backend dispatch.
 
-`backend="auto"` picks the Pallas kernel on TPU and the pure-jnp oracle on
-CPU (where `interpret=True` Pallas is a Python-level interpreter and much
-slower than XLA:CPU).  Tests force `backend="pallas"` with interpret mode
-to validate the kernels against the oracles.
+`backend="auto"` picks the compiled Pallas kernel on TPU and the pure-jnp
+oracle elsewhere (where `interpret=True` Pallas is a Python-level
+interpreter and much slower than XLA:CPU).  On a TPU a kernel either
+compiles or raises: nothing falls back to the interpreter or the oracle.
+An explicit `backend="pallas"` off the TPU runs the interpreter — the tests
+use it to validate the kernels against the oracles.
 """
 
 from __future__ import annotations
@@ -22,13 +24,21 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _use_pallas(backend: str) -> bool:
+    """Resolve `backend` ("auto" | "pallas" | "ref") to a kernel choice."""
+    if backend == "auto":
+        return _on_tpu()
+    if backend not in ("pallas", "ref"):
+        raise ValueError(f"backend={backend!r}: expected 'auto', 'pallas' "
+                         "or 'ref'")
+    return backend == "pallas"
+
+
 @functools.partial(jax.jit, static_argnames=("dt", "backend"))
 def rc_multistep(c, g_branch, g_clamp, v_clamp, v0, ramp, dt,
                  backend: str = "auto"):
     """Batched RC-ladder implicit-Euler transient -> (T, B, N) trace."""
-    if backend == "auto":
-        backend = "pallas" if _on_tpu() else "ref"
-    if backend == "pallas":
+    if _use_pallas(backend):
         return rc_multistep_pallas(c, g_branch, g_clamp, v_clamp, v0, ramp,
                                    dt, interpret=not _on_tpu())
     return ref.rc_multistep_ref(c, g_branch, g_clamp, v_clamp, v0, ramp, dt)
@@ -43,9 +53,7 @@ def row_cycle_fused(c, g_branch, gc_res, gc_pre, v0, params, dt,
     Trace-free: O(B) outputs regardless of the number of time steps.  See
     `ref.row_cycle_fused_ref` for the params layout and event semantics.
     """
-    if backend == "auto":
-        backend = "pallas" if _on_tpu() else "ref"
-    if backend == "pallas":
+    if _use_pallas(backend):
         return row_cycle_fused_pallas(c, g_branch, gc_res, gc_pre, v0,
                                       params, dt, n_act, n_res, n_pre,
                                       interpret=not _on_tpu())
@@ -63,9 +71,7 @@ def strap_attend(q, k_pages, v_pages, strap_ids, pages_per_strap,
     filled strap and are masked out of the softmax.  `None` attends every
     token of every selected strap (all-valid).
     """
-    if backend == "auto":
-        backend = "pallas" if _on_tpu() else "ref"
-    if backend == "pallas":
+    if _use_pallas(backend):
         return strap_attend_pallas(q, k_pages, v_pages, strap_ids,
                                    pages_per_strap, scale,
                                    lengths=lengths,

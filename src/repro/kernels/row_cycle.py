@@ -36,8 +36,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .ref import _thomas_small
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_B_BLK = 128
 
@@ -72,32 +71,34 @@ ROLE_MAIN = 2.0
 def _row_cycle_kernel(c_ref, g_ref, gcr_ref, gcp_ref, v0_ref, par_ref,
                       evt_ref, vend_ref, *, n_act: int, n_res: int,
                       n_pre: int, dt: float):
-    """One batch-block: phase state machine until every point is DONE."""
-    c = c_ref[...]                 # (B_blk, N)
-    g_br = g_ref[...]              # (B_blk, N-1)
-    gc_res = gcr_ref[...]          # (B_blk, N)
-    gc_pre = gcp_ref[...]          # (B_blk, N)
-    tau = jnp.maximum(par_ref[..., PAR_TAU_WL], 1e-3)
-    thr_rel = par_ref[..., PAR_THR_REL]
-    vdd = par_ref[..., PAR_VDD]
-    vpre = par_ref[..., PAR_VPRE]
-    active = par_ref[..., PAR_ACTIVE] > 0.5
-    role = (par_ref[..., PAR_ROLE] if par_ref.shape[-1] > PAR_ROLE
+    """One batch-block: phase state machine until every point is DONE.
+
+    Mosaic lowers neither rank-1 vectors, gathers, scatters nor stacked
+    booleans, so every per-row quantity is a (B_blk, 1) column: the ladder
+    state is a tuple of N node columns, the phase and counter are int32
+    columns, and the four event columns are stored once at the end.
+    """
+    col = lambda ref, j: ref[:, j:j + 1]                   # (B_blk, 1)
+    b, n = c_ref.shape
+    cdt = [col(c_ref, i) / dt * 1e-3 for i in range(n)]   # fF/ns -> mS
+    g_br = [col(g_ref, i) for i in range(n - 1)]
+    gc_res = [col(gcr_ref, i) for i in range(n)]
+    gc_pre = [col(gcp_ref, i) for i in range(n)]
+    tau = jnp.maximum(col(par_ref, PAR_TAU_WL), 1e-3)
+    thr_rel = col(par_ref, PAR_THR_REL)
+    vdd = col(par_ref, PAR_VDD)
+    vpre = col(par_ref, PAR_VPRE)
+    active = col(par_ref, PAR_ACTIVE) > 0.5
+    role = (col(par_ref, PAR_ROLE) if par_ref.shape[-1] > PAR_ROLE
             else jnp.zeros_like(thr_rel))   # static: role column presence
     is_rep = jnp.abs(role - 1.0) < 0.5
     is_main = role > 1.5
-    b, n = c.shape
-    cdt = c / dt * 1e-3            # fF/ns = uS -> mS (match G in 1/kOhm)
     t_total = n_act + n_res + n_pre
-    n_phase = jnp.stack([
-        jnp.full((b,), n_act, jnp.int32),
-        jnp.full((b,), n_res, jnp.int32),
-        jnp.full((b,), n_pre, jnp.int32),
-    ])
+    nan = jnp.float32(jnp.nan)
 
     def cond(state):
-        t, phase, _, _, _ = state
-        return jnp.logical_and(t < t_total, jnp.any(phase < 3))
+        t, phase = state[0], state[1]
+        return jnp.logical_and(t < t_total, jnp.min(phase) < 3)
 
     def body(state):
         t, phase, tin, v, evt = state
@@ -112,57 +113,61 @@ def _row_cycle_kernel(c_ref, g_ref, gcr_ref, gcp_ref, v0_ref, par_ref,
         s = jnp.where(in_act, 1.0 - e,
                       jnp.where(in_res, 1.0, jnp.where(in_pre, e, 0.0)))
 
-        # per-phase clamp network (ACT has none)
-        gc = jnp.where(in_res[:, None], gc_res,
-                       jnp.where(in_pre[:, None], gc_pre, 0.0))
-        gcv = jnp.where(in_res[:, None], gc_res * vdd[:, None],
-                        jnp.where(in_pre[:, None],
-                                  gc_pre * vpre[:, None], 0.0))
+        # tridiagonal A = C/dt + G(s) + clamp, access branch scaled by s;
+        # Thomas forward sweep (same operation order as ref._thomas_small)
+        g = g_br[:n - 2] + [g_br[n - 2] * s]
+        cp, dp = [], []
+        for i in range(n):
+            gc = jnp.where(in_res, gc_res[i],
+                           jnp.where(in_pre, gc_pre[i], 0.0))
+            gcv = jnp.where(in_res, gc_res[i] * vdd,
+                            jnp.where(in_pre, gc_pre[i] * vpre, 0.0))
+            lo = g[i - 1] if i > 0 else 0.0
+            hi = g[i] if i < n - 1 else 0.0
+            diag = cdt[i] + lo + hi + gc
+            rhs = cdt[i] * v[i] + gcv
+            if i == 0:
+                cp.append(-hi / diag)
+                dp.append(rhs / diag)
+            else:
+                denom = diag + lo * cp[i - 1]
+                cp.append(-hi / denom)
+                dp.append((rhs + lo * dp[i - 1]) / denom)
+        x = [None] * n
+        x[n - 1] = dp[n - 1]
+        for i in range(n - 2, -1, -1):
+            x[i] = dp[i] - cp[i] * x[i + 1]
+        v_next = tuple(jnp.where(done, v[i], x[i]) for i in range(n))
 
-        # tridiagonal assembly: A = C/dt + G(s); access branch scaled by s
-        g_last = g_br[:, n - 2] * s
-        g = jnp.concatenate([g_br[:, : n - 2], g_last[:, None]], axis=1)
-        zeros = jnp.zeros_like(c[:, :1])
-        g_lo = jnp.concatenate([zeros, g], axis=1)
-        g_hi = jnp.concatenate([g, zeros], axis=1)
-        diag = cdt + g_lo + g_hi + gc
-        dl = jnp.concatenate([zeros, -g], axis=1)
-        du = jnp.concatenate([-g, zeros], axis=1)
-        rhs = cdt * v + gcv
-        v_sol = _thomas_small(dl, diag, du, rhs)
-        v_next = jnp.where(done[:, None], v, v_sol)
-
-        # threshold crossings on the fresh state.  A main row's ACT
-        # crossing is the crossing of the replica at row-1 ([replica,
-        # main] pairs run ACT in lockstep, so the shift is exact).
-        cross_own = v_next[:, 0] - vpre >= thr_rel
-        cross_prev = jnp.concatenate([cross_own[-1:], cross_own[:-1]])
-        cross = jnp.stack([
-            jnp.where(is_main, cross_prev, cross_own),
-            v_next[:, n - 1] >= RESTORE_FRAC * vdd,
-            jnp.max(jnp.abs(v_next[:, : n - 1] - vpre[:, None]),
-                    axis=-1) <= EQUALIZE_TOL_V,
-        ])
+        # threshold crossings on the fresh state, as int32 columns.  A main
+        # row's ACT crossing is the crossing of the replica at row-1:
+        # [replica, main] pairs are even-aligned inside a block and run ACT
+        # in lockstep, so row 0 is a replica and the wrapped value is unused.
+        dv = v_next[0] - vpre
+        cross_own = (dv >= thr_rel).astype(jnp.int32)
+        cross_prev = pltpu.roll(cross_own, 1, 0)
+        cross_act = jnp.where(is_main, cross_prev, cross_own)
+        cross_res = (v_next[n - 1] >= RESTORE_FRAC * vdd).astype(jnp.int32)
+        dev = jnp.abs(v_next[0] - vpre)
+        for i in range(1, n - 1):
+            dev = jnp.maximum(dev, jnp.abs(v_next[i] - vpre))
+        cross_pre = (dev <= EQUALIZE_TOL_V).astype(jnp.int32)
 
         tin1 = tin + 1
-        phase_c = jnp.clip(phase, 0, 2)
-        crossed = jnp.take_along_axis(cross, phase_c[None, :], axis=0)[0]
-        cap = jnp.take_along_axis(n_phase, phase_c[None, :], axis=0)[0]
+        crossed = jnp.where(in_act, cross_act,
+                            jnp.where(in_res, cross_res, cross_pre)) > 0
+        cap = jnp.where(in_act, n_act, jnp.where(in_res, n_res, n_pre))
         advance = jnp.logical_and(~done,
                                   jnp.logical_or(crossed, tin1 >= cap))
         # first-crossing time: (idx+1)*dt, or NaN if the phase timed out
-        t_evt = jnp.where(crossed, tin1.astype(jnp.float32) * dt,
-                          jnp.float32(jnp.nan))
+        t_evt = jnp.where(crossed, tin1.astype(jnp.float32) * dt, nan)
 
         rec = lambda ph: jnp.logical_and(advance, phase == ph)
-        evt = evt.at[:, EVT_T_DEV].set(
-            jnp.where(rec(0), t_evt, evt[:, EVT_T_DEV]))
-        evt = evt.at[:, EVT_DV_SENSE].set(
-            jnp.where(rec(0), v_next[:, 0] - vpre, evt[:, EVT_DV_SENSE]))
-        evt = evt.at[:, EVT_T_RES].set(
-            jnp.where(rec(1), t_evt, evt[:, EVT_T_RES]))
-        evt = evt.at[:, EVT_T_PRE].set(
-            jnp.where(rec(2), t_evt, evt[:, EVT_T_PRE]))
+        t_dev, dv_sense, t_res, t_pre = evt
+        evt = (jnp.where(rec(0), t_evt, t_dev),
+               jnp.where(rec(0), dv, dv_sense),
+               jnp.where(rec(1), t_evt, t_res),
+               jnp.where(rec(2), t_evt, t_pre))
 
         # replica rows are ACT-only: they jump straight to DONE
         phase_inc = jnp.where(is_rep, 3, 1)
@@ -170,12 +175,15 @@ def _row_cycle_kernel(c_ref, g_ref, gcr_ref, gcp_ref, v0_ref, par_ref,
         tin = jnp.where(advance, 0, jnp.where(done, tin, tin1))
         return t + 1, phase, tin, v_next, evt
 
-    phase0 = jnp.where(active, 0, 3).astype(jnp.int32)
-    state = (jnp.int32(0), phase0, jnp.zeros((b,), jnp.int32),
-             v0_ref[...], jnp.zeros((b, N_EVENTS), jnp.float32))
+    zero = jnp.zeros((b, 1), jnp.float32)
+    state = (jnp.int32(0), jnp.where(active, 0, 3).astype(jnp.int32),
+             jnp.zeros((b, 1), jnp.int32),
+             tuple(col(v0_ref, i) for i in range(n)), (zero,) * N_EVENTS)
     _, _, _, v_fin, evt_fin = jax.lax.while_loop(cond, body, state)
-    evt_ref[...] = evt_fin
-    vend_ref[...] = v_fin
+    for k in range(N_EVENTS):
+        evt_ref[:, k:k + 1] = evt_fin[k]
+    for i in range(n):
+        vend_ref[:, i:i + 1] = v_fin[i]
 
 
 def row_cycle_fused_pallas(c: jnp.ndarray, g_branch: jnp.ndarray,

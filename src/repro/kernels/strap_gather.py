@@ -8,6 +8,8 @@ unselected local bitlines off the global line.  HBM bytes per decoded token
 drop by the strap selectivity (the C_BL 20 fF -> 6.6 fF analogue).
 
 Layout / schedule:
+  K/V are relaid head-major, (B, Hkv, P*page, D), so a strap is one
+  (G*page, D) block whose last two dims meet the TPU (8, 128) tiling.
   grid = (B, Hkv, S)          S = number of selected straps per sequence
   The strap axis is the innermost (sequential, "arbitrary") grid dim; the
   kernel keeps the online-softmax state (m, l, o-accumulator) for the
@@ -18,8 +20,8 @@ Layout / schedule:
   granularity — i.e. the gather *is* the block index map; no materialized
   gathered copy ever exists in HBM.
 
-q heads are grouped GQA-style: the (Hq/Hkv) query heads of a kv head are
-processed together as the sublane axis of the (grp, page*G? no — strap) tile.
+q heads are grouped GQA-style: the Hq/Hkv query heads of a kv head form
+one (grp, D) query tile against the strap's (G*page, D) K/V tile.
 Masked straps (id < 0) contribute nothing (handled by -inf masking).
 """
 
@@ -30,22 +32,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-
-
-def _compiler_params(**kw):
-    """TPU compiler params across jax versions (CompilerParams was renamed)."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kw)
 
 
 def _strap_kernel(strap_ids_ref,          # scalar prefetch: (B, S)
                   lengths_ref,            # scalar prefetch: (B,)
                   q_ref,                  # (1, grp, D)
-                  k_ref,                  # (1, G*page, 1, D)
-                  v_ref,                  # (1, G*page, 1, D)
+                  k_ref,                  # (1, 1, G*page, D)
+                  v_ref,                  # (1, 1, G*page, D)
                   o_ref,                  # (1, grp, D)
                   m_ref, l_ref, acc_ref,  # VMEM scratch
                   *, scale: float, num_straps: int, blk: int):
@@ -62,10 +58,12 @@ def _strap_kernel(strap_ids_ref,          # scalar prefetch: (B, S)
     valid = strap_id >= 0
 
     q = q_ref[0, 0].astype(jnp.float32)                 # (grp, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)           # (T_blk, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)           # (T_blk, D)
+    k = k_ref[0, 0].astype(jnp.float32)                 # (T_blk, D)
+    v = v_ref[0, 0].astype(jnp.float32)                 # (T_blk, D)
 
-    logits = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    logits = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale     # (grp, T_blk)
     # token-level mask: a partially filled strap has zero-padding tokens at
     # flat positions >= lengths[b]; their logit would be a perfectly valid
     # q.0 = 0 and they'd steal softmax mass, so mask them like the dense path
@@ -119,10 +117,12 @@ def strap_attend_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
     if scale is None:
         scale = 1.0 / (d ** 0.5)
 
-    # flatten pages to a token axis; a strap is a contiguous block of G*page
-    # tokens, so the index map can address it directly.
-    k_flat = k_pages.reshape(b, p * page, hkv, d)
-    v_flat = v_pages.reshape(b, p * page, hkv, d)
+    # flatten pages to a head-major token axis (B, Hkv, P*page, D): a strap
+    # is a contiguous block of G*page tokens, so the index map can address
+    # it directly, and the block's last two dims (G*page, D) meet the TPU
+    # tiling rule.  The relayout is one pass over the cache per call.
+    k_flat = k_pages.reshape(b, p * page, hkv, d).transpose(0, 2, 1, 3)
+    v_flat = v_pages.reshape(b, p * page, hkv, d).transpose(0, 2, 1, 3)
     q_g = q.reshape(b, hkv, grp, d)
     blk = g * page
 
@@ -140,21 +140,19 @@ def strap_attend_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
 
     def kv_map(bi, hi, si, ids, lens):
         del lens
-        return (bi, jnp.maximum(ids[bi, si], 0), hi, 0)
+        return (bi, hi, jnp.maximum(ids[bi, si], 0), 0)
 
     def o_map(bi, hi, si, ids, lens):
         del ids, lens, si
         return (bi, hi, 0, 0)
-
-    from jax.experimental.pallas import tpu as pltpu
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, hkv, s),
         in_specs=[
             pl.BlockSpec((1, 1, grp, d), q_map),
-            pl.BlockSpec((1, blk, 1, d), kv_map),
-            pl.BlockSpec((1, blk, 1, d), kv_map),
+            pl.BlockSpec((1, 1, blk, d), kv_map),
+            pl.BlockSpec((1, 1, blk, d), kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, grp, d), o_map),
         scratch_shapes=[
@@ -171,7 +169,7 @@ def strap_attend_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, grp, d), q.dtype),
         interpret=interpret,
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(raw_ids, lengths, q_g, k_flat, v_flat)
     return out.reshape(b, hq, d)

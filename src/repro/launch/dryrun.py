@@ -1,8 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512")
-# The two lines above MUST run before any jax import (jax locks the device
-# count on first init).  Everything below is ordinary.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST run before any jax import (jax locks the device
+# count on first init).  The dry-run is a CPU-only rehearsal on 512 forced
+# host devices, never a chip run.  Everything below is ordinary.
 
 """Multi-pod dry-run: lower + compile every (arch x shape-cell x mesh).
 

@@ -25,6 +25,9 @@ On CPU CI the children therefore dispatch their slabs on their LOCAL
 device mesh — which, by claim 3 (asserted, not assumed), is the same
 computation the global mesh would shard across hosts.
 
+This is a CPU-only rehearsal: the children are pinned to
+JAX_PLATFORMS=cpu with forced host devices and never touch a chip.
+
 CLI:  python -m repro.launch.multiproc --smoke         (the CI entry)
       ... --smoke --mc 16 --local-devices 4            (bigger variant)
 """

@@ -253,25 +253,6 @@ def serve_requests(objs, args) -> int:
     return status
 
 
-def _batches_identical(a, b) -> bool:
-    """NaN-aware bit-identity over every array field + corner channel."""
-    import numpy as np
-
-    from ..core.batch import ARRAY_FIELDS
-
-    def eq(x, y):
-        x, y = np.asarray(x), np.asarray(y)
-        if x.shape != y.shape or x.dtype != y.dtype:
-            return False
-        if x.dtype.kind == "f":
-            return bool(((x == y) | (np.isnan(x) & np.isnan(y))).all())
-        return bool((x == y).all())
-
-    return (set(a.corners) == set(b.corners)
-            and all(eq(getattr(a, f), getattr(b, f)) for f in ARRAY_FIELDS)
-            and all(eq(a.corners[k], b.corners[k]) for k in a.corners))
-
-
 def _smoke(window_ms: float) -> None:
     """The ci_check serving smoke: a warm engine serving two concurrent
     clients' mixed sweep/yield queries from ONE shared fused dispatch,
@@ -280,6 +261,7 @@ def _smoke(window_ms: float) -> None:
     import time
 
     from ..core import dse
+    from ..core.batch import batches_identical
     from ..core.space import DesignSpace
     from ..serving.dse_service import DSEService
 
@@ -323,10 +305,10 @@ def _smoke(window_ms: float) -> None:
 
     r_sweep = futures["sweep"].result(timeout=0)
     r_yield = futures["yield"].result(timeout=0)
-    if not _batches_identical(r_sweep.batch, dse.sweep(s_sweep)):
+    if not batches_identical(r_sweep.batch, dse.sweep(s_sweep)):
         raise SystemExit("serve smoke: packed sweep response is NOT "
                          "bit-identical to direct dse.sweep")
-    if not _batches_identical(r_yield.batch, dse.sweep(s_yield)):
+    if not batches_identical(r_yield.batch, dse.sweep(s_yield)):
         raise SystemExit("serve smoke: packed yield response is NOT "
                          "bit-identical to direct dse.sweep")
     if r_yield.summary is None or "yield_frac" not in r_yield.summary.corners:
@@ -345,13 +327,13 @@ def _smoke(window_ms: float) -> None:
     if final["dispatches"] != after["dispatches"]:
         raise SystemExit("serve smoke: repeated query re-dispatched "
                          "instead of answering from the memo")
-    if not _batches_identical(r_again.batch, r_sweep.batch):
+    if not batches_identical(r_again.batch, r_sweep.batch):
         raise SystemExit("serve smoke: memo hit returned a different batch")
 
     # background dispatcher liveness: blocking clients through the thread
     with DSEService(window_ms=window_ms) as bg:
         live = bg.sweep(s_sweep, timeout=60.0)
-    if not _batches_identical(live, r_sweep.batch):
+    if not batches_identical(live, r_sweep.batch):
         raise SystemExit("serve smoke: dispatcher-thread result diverged")
     print(f"memo smoke: repeat answered from memo "
           f"(hit rate {final['memo']['hit_rate']:.2f}, "
@@ -402,4 +384,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from ..runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

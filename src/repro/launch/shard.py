@@ -43,7 +43,6 @@ import functools
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core import contracts, transient
@@ -155,8 +154,9 @@ def _sharded_engine(mesh: Mesh, backend: str, b_chunk: int):
         return (evt.reshape(slab, *evt.shape[2:]),
                 v_end.reshape(slab, *v_end.shape[2:]))
 
-    return jax.jit(shard_map(device_fn, mesh=mesh, in_specs=(spec,) * 6,
-                             out_specs=(spec, spec), check_rep=False))
+    return jax.jit(jax.shard_map(device_fn, mesh=mesh,
+                                 in_specs=(spec,) * 6,
+                                 out_specs=(spec, spec), check_vma=False))
 
 
 def row_cycle_fused_sharded(operands, sharding=None, backend: str = "auto",
@@ -208,9 +208,9 @@ def _sharded_scorer(mesh: Mesh):
     from ..core import dse
     axis = mesh.axis_names
     in_specs = (P(axis), P(axis), P(axis), P(axis), P(axis, None))
-    return jax.jit(shard_map(dse.score_from_events, mesh=mesh,
-                             in_specs=in_specs, out_specs=P(axis),
-                             check_rep=False))
+    return jax.jit(jax.shard_map(dse.score_from_events, mesh=mesh,
+                                 in_specs=in_specs, out_specs=P(axis),
+                                 check_vma=False))
 
 
 def _gather_columns(cols: dict, b: int) -> dict:
@@ -328,8 +328,8 @@ def _sharded_pareto_engine(mesh: Mesh, block: int):
         return jax.lax.psum(dominated.astype(jnp.int32), axis) > 0
 
     in_specs = (P(axis, None), P(axis, None), P(axis), P(), P(), P())
-    return jax.jit(shard_map(device_fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=P(), check_rep=False))
+    return jax.jit(jax.shard_map(device_fn, mesh=mesh, in_specs=in_specs,
+                                 out_specs=P(), check_vma=False))
 
 
 def sharded_pareto_dominated(hi, lo, cand, sharding=None,

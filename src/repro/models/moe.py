@@ -115,7 +115,6 @@ def moe_apply_ep(cfg, p, x):
     combine.  Capacity is enforced per (device, expert) — the standard EP
     semantics (local drops instead of global).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..distributed import context as mesh_ctx
@@ -210,6 +209,6 @@ def moe_apply_ep(cfg, p, x):
     else:
         fn = lambda xl, r, wg, wu, wd: local(xl, r, wg, wu, wd)
 
-    mapped = shard_map(fn, mesh=mesh, in_specs=tuple(in_specs),
-                       out_specs=(x_spec, P()), check_rep=False)
+    mapped = jax.shard_map(fn, mesh=mesh, in_specs=tuple(in_specs),
+                           out_specs=(x_spec, P()), check_vma=False)
     return mapped(*args)

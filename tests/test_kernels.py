@@ -173,6 +173,14 @@ class TestRowCycleFusedKernel:
         np.testing.assert_allclose(t0, n_cross * self.DT, rtol=1e-6)
 
 
+def test_unknown_backend_raises(rng):
+    """A misspelt backend is an error, never a silent fall to the oracle."""
+    from repro.kernels import ops
+    args = random_row_cycle_inputs(rng, 8, 6)
+    with pytest.raises(ValueError, match="backend='pallsa'"):
+        ops.row_cycle_fused(*args, 0.02, 4, 4, 4, backend="pallsa")
+
+
 class TestTridiag:
     @pytest.mark.parametrize("b,n", [(1, 3), (5, 7), (16, 32)])
     def test_vs_dense_solve(self, rng, b, n):

@@ -64,8 +64,6 @@ MINI_DRYRUN = textwrap.dedent("""
         with mesh:
             compiled = jt.lower(abs_p, abs_o, batch).compile()
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):   # older jax returns [dict]
-            ca = ca[0] if ca else {}
         flops = ca.get("flops", -1)
         # decode path too
         bsz, seq = 2, 128
