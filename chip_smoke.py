@@ -66,37 +66,11 @@ def log(phase: str, **fields) -> None:
     print(f"{phase}: {json.dumps(fields, sort_keys=True)}", flush=True)
 
 
-class CompileMeter:
-    """Seconds spent obtaining executables (compiles and persistent-cache
-    loads), from JAX's own monitoring events.  Registered once."""
-
-    _instance = None
-
-    def __init__(self):
-        import jax
-        self.seconds = 0.0
-        self.programs = 0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    @classmethod
-    def get(cls) -> "CompileMeter":
-        if cls._instance is None:
-            cls._instance = cls()
-        return cls._instance
-
-    def _duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-            self.programs += 1
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def snapshot(self) -> tuple[float, int, int]:
-        return self.seconds, self.programs, self.cache_hits
+def compile_totals() -> dict:
+    """Programs compiled, their seconds, and persistent-cache loads since
+    `repro.obs` was imported (its process totals of JAX's compile events)."""
+    from repro import obs
+    return obs.summary()["compile"]
 
 
 def device_info() -> dict:
@@ -189,15 +163,16 @@ def study_space(samples: int):
 def _timed_sweep(space, **kw):
     import jax
     from repro.core import dse
-    meter = CompileMeter.get()
-    c0, p0, h0 = meter.snapshot()
+    before = compile_totals()
     t0 = time.perf_counter()
     batch = dse.sweep(space, **kw)
     jax.block_until_ready(batch.trc_ns)
     wall = time.perf_counter() - t0
-    c1, p1, h1 = meter.snapshot()
-    return batch, {"wall_s": wall, "compile_s": c1 - c0,
-                   "programs": p1 - p0, "cache_hits": h1 - h0}
+    after = compile_totals()
+    return batch, {"wall_s": wall,
+                   "compile_s": after["seconds"] - before["seconds"],
+                   "programs": after["programs"] - before["programs"],
+                   "cache_hits": after["cache_loads"] - before["cache_loads"]}
 
 
 def phase_sweep(samples: int = STUDY_SAMPLES, backend: str = "auto") -> dict:
@@ -394,7 +369,7 @@ def main(argv=None) -> int:
 
     from repro.runtime.compile_cache import enable_compile_cache
     log("compile_cache", dir=enable_compile_cache())
-    CompileMeter.get()
+    start = compile_totals()
     t_start = time.perf_counter()
     phases = ([("sharded", lambda: phase_sharded(n_dev=args.chips))]
               if args.chips > 1 else
@@ -409,10 +384,11 @@ def main(argv=None) -> int:
             print(f"chip_smoke: FAIL {name}: {e}", file=sys.stderr)
             return 1
         log(name, phase_s=time.perf_counter() - t0, **out)
-    meter = CompileMeter.get()
+    end = compile_totals()
     log("total", wall_s=time.perf_counter() - t_start,
-        compile_s=meter.seconds, programs=meter.programs,
-        cache_hits=meter.cache_hits)
+        compile_s=end["seconds"] - start["seconds"],
+        programs=end["programs"] - start["programs"],
+        cache_hits=end["cache_loads"] - start["cache_loads"])
     print(json.dumps({"ok": True, "device": info}), flush=True)
     return 0
 

@@ -32,6 +32,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from . import calibration as cal
 from . import contracts
 from .batch import DesignBatch, DesignPoint
@@ -102,19 +103,23 @@ def plan_sweep(space: DesignSpace | None = None,
     """
     if space is None:
         space = DesignSpace.paper_grid()
-    sp = space.lower()
-    unknown = [k for k in sp.corners
-               if k not in SUPPORTED_CORNER_AXES and k not in MC_AXES
-               and k != MC_LOG_W]
-    if unknown:
-        raise ValueError(f"unsupported corner axes {unknown}; sweep "
-                         f"understands {SUPPORTED_CORNER_AXES}")
-    par = bl_parasitics_lowered(sp)
-    operands = None
-    if with_transient:
-        ladder_c, ladder_g = build_ladder_lowered(sp, par)
-        operands = transient.lower_design_operands(
-            sp, ladder_c=ladder_c, ladder_g=ladder_g)
+    with obs.span("dse.plan"):
+        with obs.span("dse.plan.lower"):
+            sp = space.lower()
+        unknown = [k for k in sp.corners
+                   if k not in SUPPORTED_CORNER_AXES and k not in MC_AXES
+                   and k != MC_LOG_W]
+        if unknown:
+            raise ValueError(f"unsupported corner axes {unknown}; sweep "
+                             f"understands {SUPPORTED_CORNER_AXES}")
+        with obs.span("dse.plan.parasitics"):
+            par = bl_parasitics_lowered(sp)
+        operands = None
+        if with_transient:
+            with obs.span("dse.plan.operands"):
+                ladder_c, ladder_g = build_ladder_lowered(sp, par)
+                operands = transient.lower_design_operands(
+                    sp, ladder_c=ladder_c, ladder_g=ladder_g)
     return SweepPlan(space=space, sp=sp, par=par, operands=operands)
 
 
@@ -250,21 +255,22 @@ def finalize_sweep(plan: SweepPlan,
             "finalize_sweep needs the fused-engine result exactly when "
             "the plan lowered transient operands (with_transient="
             f"{plan.with_transient}, res={'set' if res is not None else 'None'})")
-    view = SpaceView.from_lowered(plan.sp)
-    cbl = jnp.asarray(plan.par.c_bl_total_ff, jnp.float32)
-    if res is None:
-        cols = _score_columns_jit(view, cbl)
-    elif res.events is not None:
-        cols = _score_from_events_jit(
-            view, cbl, plan.operands.sa_tau_ns, plan.operands.t_overhead_ns,
-            res.events)
-    else:
-        # result built without raw events (legacy construction): score
-        # from the rolled-up columns; matches the events path up to the
-        # compiler's instruction scheduling of the rollup.
-        cols = _score_columns_jit(view, cbl, res.trc_ns, res.t_sense_ns,
-                                  res.t_fire_ns, res.dv_sense_v)
-    return assemble_batch(plan.sp, cols)
+    with obs.span("dse.finalize"):
+        view = SpaceView.from_lowered(plan.sp)
+        cbl = jnp.asarray(plan.par.c_bl_total_ff, jnp.float32)
+        if res is None:
+            cols = _score_columns_jit(view, cbl)
+        elif res.events is not None:
+            cols = _score_from_events_jit(
+                view, cbl, plan.operands.sa_tau_ns,
+                plan.operands.t_overhead_ns, res.events)
+        else:
+            # result built without raw events (legacy construction): score
+            # from the rolled-up columns; matches the events path up to the
+            # compiler's instruction scheduling of the rollup.
+            cols = _score_columns_jit(view, cbl, res.trc_ns, res.t_sense_ns,
+                                      res.t_fire_ns, res.dv_sense_v)
+        return assemble_batch(plan.sp, cols)
 
 
 def sweep(space: DesignSpace | None = None, with_transient: bool = True,
@@ -294,17 +300,18 @@ def sweep(space: DesignSpace | None = None, with_transient: bool = True,
             "sharding= only distributes the fused transient dispatch; a "
             "with_transient=False sweep is host-side array ops with "
             "nothing to shard — pass sharding=None")
-    plan = plan_sweep(space, with_transient=with_transient)
-    if plan.operands is not None and sharding is not None:
-        from ..launch import shard
-        cols = shard.sharded_sweep_columns(plan, sharding, backend=backend,
-                                           b_chunk=b_chunk)
-        return assemble_batch(plan.sp, cols)
-    res = None
-    if plan.operands is not None:
-        res = simulate_row_cycle_many(plan.operands, backend=backend,
-                                      b_chunk=b_chunk)
-    return finalize_sweep(plan, res)
+    with obs.span("dse.sweep"):
+        plan = plan_sweep(space, with_transient=with_transient)
+        if plan.operands is not None and sharding is not None:
+            from ..launch import shard
+            cols = shard.sharded_sweep_columns(plan, sharding, backend=backend,
+                                               b_chunk=b_chunk)
+            return assemble_batch(plan.sp, cols)
+        res = None
+        if plan.operands is not None:
+            res = simulate_row_cycle_many(plan.operands, backend=backend,
+                                          b_chunk=b_chunk)
+        return finalize_sweep(plan, res)
 
 
 # ---------------------------------------------------------------------------

@@ -33,8 +33,10 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 
+from .. import obs
 from . import calibration as cal
 from . import contracts
 from .calibration import TechCal
@@ -273,6 +275,12 @@ def validate_b_chunk(b_chunk: int) -> int:
     return b_chunk
 
 
+@jax.jit
+def _sum_block_steps(parts):
+    """Total of every launch's per-block step counts: one device op."""
+    return jnp.concatenate(parts).sum()
+
+
 def _row_cycle_fused_chunked(operands, backend: str, b_chunk: int):
     """Feed (c, g, gc_res, gc_pre, v0, params) through the fused engine in
     fixed-size chunks so arbitrary sweep grids fit VMEM/HBM.
@@ -280,29 +288,43 @@ def _row_cycle_fused_chunked(operands, backend: str, b_chunk: int):
     Every call is padded with inactive design points to a B_ALIGN multiple
     no larger than `b_chunk` (which must itself be a B_ALIGN multiple), so
     calls share compiled shapes and never exceed the caller's memory bound.
+
+    Runs as the `engine.dispatch` span (`repro.obs`), which counts the
+    kernel `launches`, the `rows` given, the `rows_padded` after chunk and
+    block padding, and the kernel's own `block_steps`, summed on the
+    device after the last launch.  Nothing here reads a device value; the
+    runtime may still hold an enqueue while earlier launches run.
     """
     b_chunk = validate_b_chunk(b_chunk)
-    c = operands[0]
-    b = c.shape[0]
-    if b <= b_chunk:
-        target = min(-(-b // B_ALIGN) * B_ALIGN, b_chunk)
-        padded = _pad_operands(operands, target - b)
-        evt, v_end = ops.row_cycle_fused(*padded, DT_NS, N_ACT_STEPS,
-                                         N_RESTORE_STEPS, N_PRE_STEPS,
-                                         backend=backend)
-        return evt[:b], v_end[:b]
-    pad = (-b) % b_chunk
-    ops_padded = _pad_operands(operands, pad)
-    evts, vends = [], []
-    for lo in range(0, b + pad, b_chunk):
-        chunk = [x[lo:lo + b_chunk] for x in ops_padded]
-        evt, v_end = ops.row_cycle_fused(*chunk, DT_NS, N_ACT_STEPS,
-                                         N_RESTORE_STEPS, N_PRE_STEPS,
-                                         backend=backend)
-        evts.append(evt)
-        vends.append(v_end)
-    return (jnp.concatenate(evts, axis=0)[:b],
-            jnp.concatenate(vends, axis=0)[:b])
+    b = operands[0].shape[0]
+    with obs.span("engine.dispatch"):
+        if b <= b_chunk:
+            rows = min(-(-b // B_ALIGN) * B_ALIGN, b_chunk)
+            chunks = [_pad_operands(operands, rows - b)]
+        else:
+            rows = b_chunk
+            pad = (-b) % b_chunk
+            ops_padded = _pad_operands(operands, pad)
+            chunks = ([x[lo:lo + b_chunk] for x in ops_padded]
+                      for lo in range(0, b + pad, b_chunk))
+        outs = [ops.row_cycle_fused(*chunk, DT_NS, N_ACT_STEPS,
+                                    N_RESTORE_STEPS, N_PRE_STEPS,
+                                    backend=backend)
+                for chunk in chunks]
+        if len(outs) == 1:
+            evt, v_end = outs[0][0][:b], outs[0][1][:b]
+        else:
+            evt = jnp.concatenate([o[0] for o in outs], axis=0)[:b]
+            v_end = jnp.concatenate([o[1] for o in outs], axis=0)[:b]
+        block = ops.row_cycle_block_rows(rows, backend)
+        obs.count("launches", len(outs))
+        obs.count("rows", b)
+        obs.count("rows_padded", len(outs) * -(-rows // block) * block)
+        # a seam replaced by a plain (events, v_end) pair reports no count
+        steps = [getattr(o, "block_steps", None) for o in outs]
+        if None not in steps:
+            obs.count("block_steps", _sum_block_steps(steps))
+    return evt, v_end
 
 
 def simulate_row_cycle(tech: TechCal, scheme: str, layers,

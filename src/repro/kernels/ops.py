@@ -16,7 +16,7 @@ import jax
 
 from . import ref
 from .rc_transient import rc_multistep_pallas
-from .row_cycle import row_cycle_fused_pallas
+from .row_cycle import DEFAULT_B_BLK, row_cycle_fused_pallas
 from .strap_gather import strap_attend_pallas
 
 
@@ -48,7 +48,9 @@ def rc_multistep(c, g_branch, g_clamp, v_clamp, v0, ramp, dt,
                                              "n_pre", "backend"))
 def row_cycle_fused(c, g_branch, gc_res, gc_pre, v0, params, dt,
                     n_act, n_res, n_pre, backend: str = "auto"):
-    """Fused ACT/RESTORE/PRE row-cycle engine -> (events (B,4), v_end (B,N)).
+    """Fused ACT/RESTORE/PRE row-cycle engine -> `ref.RowCycleOut`:
+    (events (B,4), v_end (B,N)), with the step count of each batch block
+    (`row_cycle_block_rows` rows) as `.block_steps`.
 
     Trace-free: O(B) outputs regardless of the number of time steps.  See
     `ref.row_cycle_fused_ref` for the params layout and event semantics.
@@ -59,6 +61,12 @@ def row_cycle_fused(c, g_branch, gc_res, gc_pre, v0, params, dt,
                                       interpret=not _on_tpu())
     return ref.row_cycle_fused_ref(c, g_branch, gc_res, gc_pre, v0, params,
                                    dt, n_act, n_res, n_pre)
+
+
+def row_cycle_block_rows(n: int, backend: str = "auto") -> int:
+    """Rows per batch block of a `row_cycle_fused` call over n rows: the
+    Pallas kernel's block, or the whole batch on the oracle."""
+    return min(DEFAULT_B_BLK, n) if _use_pallas(backend) else n
 
 
 @functools.partial(jax.jit, static_argnames=("pages_per_strap", "scale", "backend"))

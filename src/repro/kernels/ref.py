@@ -117,6 +117,23 @@ ROLE_MAIN = 2.0         # main array row: SA enable fired by the replica
                         # at row-1 (rows are interleaved [replica, main])
 
 
+class RowCycleOut(tuple):
+    """What one fused row-cycle call returns: unpacks as `(events, v_end)`,
+    and carries `block_steps`, the engine's own step count of each batch
+    block, (n_blocks,) int32.  A pytree of those three arrays, so it passes
+    through `jax.jit`."""
+
+    def __new__(cls, events, v_end, block_steps):
+        out = super().__new__(cls, (events, v_end))
+        out.block_steps = block_steps
+        return out
+
+
+jax.tree_util.register_pytree_node(
+    RowCycleOut, lambda o: ((o[0], o[1], o.block_steps), None),
+    lambda _, leaves: RowCycleOut(*leaves))
+
+
 def _thomas_small(dl, d, du, rhs):
     """Thomas solve unrolled over the last (static, small) axis."""
     n = d.shape[-1]
@@ -165,8 +182,9 @@ def row_cycle_fused_ref(c: jnp.ndarray, g_branch: jnp.ndarray,
     RESTORE/PRE (phase 0 -> 3).  A main row's recorded dv_sense is its own
     developed signal at the moment the replica fires.
 
-    Returns (events, v_end): (B, 4) [t_dev, dv_sense, t_res_dur, t_pre]
-    and (B, N) final node voltages.
+    Returns a `RowCycleOut`: events (B, 4) [t_dev, dv_sense, t_res_dur,
+    t_pre], v_end (B, N) final node voltages, and `block_steps` (1,), the
+    loop's trip count with the whole batch as one block.
     """
     b, n = c.shape
     cdt = c / dt * 1e-3  # fF/ns = uS; G in 1/kOhm = mS -> 1e-3 factor
@@ -250,8 +268,8 @@ def row_cycle_fused_ref(c: jnp.ndarray, g_branch: jnp.ndarray,
     state = (jnp.int32(0), jnp.where(active, 0, 3).astype(jnp.int32),
              jnp.zeros((b,), jnp.int32), v0.astype(jnp.float32),
              jnp.zeros((b, ROW_CYCLE_N_EVENTS), jnp.float32))
-    _, _, _, v_fin, evt_fin = jax.lax.while_loop(cond, body, state)
-    return evt_fin, v_fin
+    t_fin, _, _, v_fin, evt_fin = jax.lax.while_loop(cond, body, state)
+    return RowCycleOut(evt_fin, v_fin, t_fin.reshape(1))
 
 
 # --------------------------------------------------------------------------

@@ -26,7 +26,8 @@ reference) step-for-step, so event times agree to within one dt.
 
 Grid:      (ceil(B / B_BLK),)  — batch is the only blocked axis.
 Outputs:   events (B, 4) = [t_dev_ns, dv_sense_v, t_restore_dur_ns,
-           t_pre_ns] and v_end (B, N).
+           t_pre_ns], v_end (B, N), and the `while_loop`'s trip count of
+           each block (the steps it ran until its slowest row was DONE).
 """
 
 from __future__ import annotations
@@ -37,6 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .ref import RowCycleOut
 
 DEFAULT_B_BLK = 128
 
@@ -69,14 +72,15 @@ ROLE_MAIN = 2.0
 
 
 def _row_cycle_kernel(c_ref, g_ref, gcr_ref, gcp_ref, v0_ref, par_ref,
-                      evt_ref, vend_ref, *, n_act: int, n_res: int,
+                      evt_ref, vend_ref, steps_ref, *, n_act: int, n_res: int,
                       n_pre: int, dt: float):
     """One batch-block: phase state machine until every point is DONE.
 
     Mosaic lowers neither rank-1 vectors, gathers, scatters nor stacked
     booleans, so every per-row quantity is a (B_blk, 1) column: the ladder
     state is a tuple of N node columns, the phase and counter are int32
-    columns, and the four event columns are stored once at the end.
+    columns, and the four event columns are stored once at the end.  The
+    block's trip count is stored in every row of an int32 column.
     """
     col = lambda ref, j: ref[:, j:j + 1]                   # (B_blk, 1)
     b, n = c_ref.shape
@@ -179,7 +183,8 @@ def _row_cycle_kernel(c_ref, g_ref, gcr_ref, gcp_ref, v0_ref, par_ref,
     state = (jnp.int32(0), jnp.where(active, 0, 3).astype(jnp.int32),
              jnp.zeros((b, 1), jnp.int32),
              tuple(col(v0_ref, i) for i in range(n)), (zero,) * N_EVENTS)
-    _, _, _, v_fin, evt_fin = jax.lax.while_loop(cond, body, state)
+    t_fin, _, _, v_fin, evt_fin = jax.lax.while_loop(cond, body, state)
+    steps_ref[...] = jnp.full((b, 1), t_fin, jnp.int32)
     for k in range(N_EVENTS):
         evt_ref[:, k:k + 1] = evt_fin[k]
     for i in range(n):
@@ -194,7 +199,8 @@ def row_cycle_fused_pallas(c: jnp.ndarray, g_branch: jnp.ndarray,
                            interpret: bool = True):
     """Pallas-backed equivalent of `ref.row_cycle_fused_ref`.
 
-    Returns (events, v_end) with shapes ((B, 4), (B, N)).
+    Returns a `RowCycleOut`: (events, v_end) with shapes ((B, 4), (B, N)),
+    and `block_steps` (ceil(B / b_blk),) int32, each block's trip count.
     """
     b, n = c.shape
     b_blk = min(b_blk, b)
@@ -211,16 +217,19 @@ def row_cycle_fused_pallas(c: jnp.ndarray, g_branch: jnp.ndarray,
     kernel = functools.partial(_row_cycle_kernel, n_act=n_act, n_res=n_res,
                                n_pre=n_pre, dt=dt)
     bspec = lambda w: pl.BlockSpec((b_blk, w), lambda i: (i, 0))
-    events, v_end = pl.pallas_call(
+    events, v_end, steps = pl.pallas_call(
         kernel,
         grid=(n_blocks,),
         in_specs=[bspec(n), bspec(n - 1), bspec(n), bspec(n), bspec(n),
                   bspec(params.shape[1])],  # (B, 5) legacy or (B, 6)
-        out_specs=[bspec(N_EVENTS), bspec(n)],
+        out_specs=[bspec(N_EVENTS), bspec(n), bspec(1)],
         out_shape=[
             jax.ShapeDtypeStruct((n_blocks * b_blk, N_EVENTS), jnp.float32),
             jax.ShapeDtypeStruct((n_blocks * b_blk, n), c.dtype),
+            jax.ShapeDtypeStruct((n_blocks * b_blk, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="row_cycle_fused",
     )(c, g_branch, gc_res, gc_pre, v0, params)
-    return events[:b], v_end[:b]
+    return RowCycleOut(events[:b], v_end[:b],
+                       steps.reshape(n_blocks, b_blk)[:, 0])

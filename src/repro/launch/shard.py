@@ -138,9 +138,11 @@ def _sharded_engine(mesh: Mesh, backend: str, b_chunk: int):
     spec = P(mesh.axis_names, None)
 
     def one_chunk(args):
-        return ops.row_cycle_fused(*args, DT_NS, N_ACT_STEPS,
-                                   N_RESTORE_STEPS, N_PRE_STEPS,
-                                   backend=backend)
+        # (events, v_end): the kernel's block step count is not kept here
+        evt, v_end = ops.row_cycle_fused(*args, DT_NS, N_ACT_STEPS,
+                                         N_RESTORE_STEPS, N_PRE_STEPS,
+                                         backend=backend)
+        return evt, v_end
 
     def device_fn(c, g, gc_res, gc_pre, v0, params):
         slab = c.shape[0]

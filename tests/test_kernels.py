@@ -83,6 +83,14 @@ def random_row_cycle_inputs(rng, b, n, dtype=np.float32):
     return tuple(map(jnp.asarray, (c, g, gc_res, gc_pre, v0, params)))
 
 
+def live_steps(evt, params, caps, dt):
+    """Steps each standalone row runs before DONE, from its event times
+    (the whole phase window where an event is NaN); 0 for inactive rows."""
+    t = np.asarray(evt)[:, [0, 2, 3]]
+    steps = np.where(np.isnan(t), caps, np.rint(t / dt)).sum(axis=1)
+    return np.where(np.asarray(params)[:, 4] > 0.5, steps, 0)
+
+
 class TestRowCycleFusedKernel:
     """Pallas fused ACT/RESTORE/PRE engine vs the jnp oracle."""
 
@@ -137,6 +145,28 @@ class TestRowCycleFusedKernel:
         np.testing.assert_array_equal(np.asarray(evt)[3:], 0.0)
         np.testing.assert_allclose(np.asarray(v_end)[3:],
                                    np.asarray(args[4])[3:])
+
+    def test_block_steps_are_each_blocks_slowest_row(self, rng):
+        """The kernel's own trip count of each block is the most live steps
+        of any of its rows, counted from the events; inactive rows and the
+        padding of the last block add nothing.  The oracle reports its own
+        loop's count, with the whole batch as one block."""
+        caps = (60, 60, 60)
+        args = list(random_row_cycle_inputs(rng, 200, 6))
+        params = np.array(args[5])
+        params[64:128, 4] = 0.0                  # block 1: no live row
+        args[5] = jnp.asarray(params)
+        out = row_cycle_fused_pallas(*args, self.DT, *caps, b_blk=64,
+                                     interpret=True)
+        live = live_steps(out[0], params, caps, self.DT)
+        want = [live[lo:lo + 64].max() for lo in range(0, 200, 64)]
+        assert out.block_steps.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(out.block_steps), want)
+        assert want[1] == 0 and 0 < min(want[::2]) < max(want) < sum(caps)
+        out_ref = ref.row_cycle_fused_ref(*args, self.DT, *caps)
+        np.testing.assert_array_equal(
+            np.asarray(out_ref.block_steps),
+            [live_steps(out_ref[0], params, caps, self.DT).max()])
 
     def test_timeout_is_nan_not_phase_window(self, rng):
         """An uncrossable ACT threshold must report NaN — an older revision

@@ -18,7 +18,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import transient
 from repro.kernels import ops
 from repro.kernels.rc_transient import rc_multistep_pallas
-from repro.kernels.row_cycle import N_PARAMS, row_cycle_fused_pallas
+from repro.kernels.row_cycle import (DEFAULT_B_BLK, N_PARAMS,
+                                     row_cycle_fused_pallas)
 from repro.kernels.strap_gather import strap_attend_pallas
 
 B, N = transient.DEFAULT_B_CHUNK, 6
@@ -85,6 +86,20 @@ def test_auto_backend_dispatches_compiled_kernel(one_chip, on_tpu):
     lowered = ops.row_cycle_fused.lower(
         *engine_operands(one_chip), transient.DT_NS, *STEPS, backend="auto")
     assert_kernel(lowered.compile())
+
+
+def test_row_cycle_kernel_counts_block_steps_on_chip(one_chip, on_tpu):
+    """The kernel's third output on the chip: one int32 step count per
+    128-row block, from the custom call named `row_cycle_fused` (the op
+    the benchmark's trace readers match)."""
+    lowered = ops.row_cycle_fused.lower(
+        *engine_operands(one_chip), transient.DT_NS, *STEPS, backend="auto")
+    steps = lowered.out_info.block_steps
+    assert steps.shape == (B // DEFAULT_B_BLK,) and steps.dtype == jnp.int32
+    text = lowered.compile().as_text()
+    kernels = [ln.split(" = ", 1)[0].strip() for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert kernels and all(k.startswith("%row_cycle_fused") for k in kernels)
 
 
 def test_sharded_engine_compiles_on_2x2(topo, on_tpu):

@@ -101,7 +101,10 @@ def record_dispatches():
     `_sharded_pareto_engine` (distributed dominance) — whose per-call
     wrappers count invocations even when the cached engine is reused.
     Tracer-valued calls — the sharded engine re-entering the patched op
-    during its own trace — are not dispatches and are skipped.
+    during its own trace — are not dispatches and are skipped.  The
+    patched op returns the engine's own `RowCycleOut` untouched: events,
+    v_end and the kernel's block step count (`.block_steps`), which the
+    chunk loop sums into its `engine.dispatch` counters.
     """
     import jax
 
@@ -507,11 +510,12 @@ def seeded_double_pallas_engine(c, g, gc_res, gc_pre, v0, params, dt,
                                 n_act, n_res, n_pre, backend="auto"):
     """An engine whose dispatch group launches TWO kernels — FC105."""
     from repro.kernels import ops
-    evt, v_end = ops.row_cycle_fused(c, g, gc_res, gc_pre, v0, params, dt,
-                                     n_act, n_res, n_pre, backend=backend)
+    from repro.kernels.ref import RowCycleOut
+    out = ops.row_cycle_fused(c, g, gc_res, gc_pre, v0, params, dt,
+                              n_act, n_res, n_pre, backend=backend)
     evt2, _ = ops.row_cycle_fused(c, g, gc_res, gc_pre, v0, params, dt,
                                   n_act, n_res, n_pre, backend=backend)
-    return evt + 0 * evt2, v_end
+    return RowCycleOut(out[0] + 0 * evt2, out[1], out.block_steps)
 
 
 SEEDED_CONFIGS = {
