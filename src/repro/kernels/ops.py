@@ -16,7 +16,7 @@ import jax
 
 from . import ref
 from .rc_transient import rc_multistep_pallas
-from .row_cycle import DEFAULT_B_BLK, row_cycle_fused_pallas
+from .row_cycle import block_rows, row_cycle_fused_pallas
 from .strap_gather import strap_attend_pallas
 
 
@@ -54,6 +54,8 @@ def row_cycle_fused(c, g_branch, gc_res, gc_pre, v0, params, dt,
 
     Trace-free: O(B) outputs regardless of the number of time steps.  See
     `ref.row_cycle_fused_ref` for the params layout and event semantics.
+    The Pallas kernel runs on (8, 128) tiles with the batch on the lanes;
+    the (B, w) <-> (w, B/128, 128) transposes live inside this jit.
     """
     if _use_pallas(backend):
         return row_cycle_fused_pallas(c, g_branch, gc_res, gc_pre, v0,
@@ -64,9 +66,10 @@ def row_cycle_fused(c, g_branch, gc_res, gc_pre, v0, params, dt,
 
 
 def row_cycle_block_rows(n: int, backend: str = "auto") -> int:
-    """Rows per batch block of a `row_cycle_fused` call over n rows: the
-    Pallas kernel's block, or the whole batch on the oracle."""
-    return min(DEFAULT_B_BLK, n) if _use_pallas(backend) else n
+    """Rows per batch block of a `row_cycle_fused` call over n rows: on the
+    Pallas kernel n rounded up to whole 128-lane sublanes, at most 1,024
+    (one (8, 128) tile per quantity); on the oracle the whole batch."""
+    return block_rows(n) if _use_pallas(backend) else n
 
 
 @functools.partial(jax.jit, static_argnames=("pages_per_strap", "scale", "backend"))

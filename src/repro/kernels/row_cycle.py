@@ -24,10 +24,22 @@ This kernel runs the *whole* row cycle in one `pallas_call`:
 Phase semantics replicate `core.transient.simulate_row_cycle` (the phased
 reference) step-for-step, so event times agree to within one dt.
 
-Grid:      (ceil(B / B_BLK),)  — batch is the only blocked axis.
+Layout: the batch lies on the lanes and sublanes of full (8, 128) f32
+tiles.  The wrapper pads B with inactive rows to whole blocks and lays each
+(B, w) operand out as (w, B/128, 128): row r sits at sublane r // 128 and
+lane r % 128, and `ref[i]` in the kernel is node (or parameter column) i of
+a whole block as one (S_blk, 128) tile.  Every per-row operation then fills
+each vreg it touches, where a (B_blk, 1) column would use one lane in 128.
+
+Grid:      (ceil(B / block rows),)  — batch is the only blocked axis.  A
+           block is `block_rows(B)` rows: B rounded up to whole 128-lane
+           sublanes, at most DEFAULT_B_BLK (8 sublanes), so a
+           2,048-row launch runs two (8, 128) blocks and a 64-row batch
+           one (1, 128) tile.
 Outputs:   events (B, 4) = [t_dev_ns, dv_sense_v, t_restore_dur_ns,
            t_pre_ns], v_end (B, N), and the `while_loop`'s trip count of
-           each block (the steps it ran until its slowest row was DONE).
+           each block (the steps it ran until its slowest row was DONE),
+           transposed back from the kernel's (w, B/128, 128) tiles.
 """
 
 from __future__ import annotations
@@ -41,7 +53,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .ref import RowCycleOut
 
-DEFAULT_B_BLK = 128
+LANES = 128
+DEFAULT_B_BLK = 1024    # 8 sublanes x 128 lanes: one f32 vreg per quantity
 
 # params (B, 6) column layout
 PAR_TAU_WL = 0      # WL driver RC time constant [ns]
@@ -71,29 +84,36 @@ ROLE_REPLICA = 1.0
 ROLE_MAIN = 2.0
 
 
+def block_rows(b: int, b_blk: int = DEFAULT_B_BLK) -> int:
+    """Rows of one batch block of a b-row launch: b rounded up to whole
+    128-lane sublanes, at most `b_blk`."""
+    return min(b_blk, pl.cdiv(b, LANES) * LANES)
+
+
 def _row_cycle_kernel(c_ref, g_ref, gcr_ref, gcp_ref, v0_ref, par_ref,
                       evt_ref, vend_ref, steps_ref, *, n_act: int, n_res: int,
                       n_pre: int, dt: float):
     """One batch-block: phase state machine until every point is DONE.
 
     Mosaic lowers neither rank-1 vectors, gathers, scatters nor stacked
-    booleans, so every per-row quantity is a (B_blk, 1) column: the ladder
-    state is a tuple of N node columns, the phase and counter are int32
-    columns, and the four event columns are stored once at the end.  The
-    block's trip count is stored in every row of an int32 column.
+    booleans, so every per-row quantity is one (S_blk, 128) tile of the
+    block's rows: the ladder state is a tuple of N node tiles, the phase
+    and counter are int32 tiles, and the four event tiles are stored once
+    at the end.  The block's trip count is stored in every lane of an
+    int32 tile.
     """
-    col = lambda ref, j: ref[:, j:j + 1]                   # (B_blk, 1)
-    b, n = c_ref.shape
-    cdt = [col(c_ref, i) / dt * 1e-3 for i in range(n)]   # fF/ns -> mS
-    g_br = [col(g_ref, i) for i in range(n - 1)]
-    gc_res = [col(gcr_ref, i) for i in range(n)]
-    gc_pre = [col(gcp_ref, i) for i in range(n)]
-    tau = jnp.maximum(col(par_ref, PAR_TAU_WL), 1e-3)
-    thr_rel = col(par_ref, PAR_THR_REL)
-    vdd = col(par_ref, PAR_VDD)
-    vpre = col(par_ref, PAR_VPRE)
-    active = col(par_ref, PAR_ACTIVE) > 0.5
-    role = (col(par_ref, PAR_ROLE) if par_ref.shape[-1] > PAR_ROLE
+    n = c_ref.shape[0]
+    tile = c_ref.shape[1:]                                 # (S_blk, 128)
+    cdt = [c_ref[i] / dt * 1e-3 for i in range(n)]        # fF/ns -> mS
+    g_br = [g_ref[i] for i in range(n - 1)]
+    gc_res = [gcr_ref[i] for i in range(n)]
+    gc_pre = [gcp_ref[i] for i in range(n)]
+    tau = jnp.maximum(par_ref[PAR_TAU_WL], 1e-3)
+    thr_rel = par_ref[PAR_THR_REL]
+    vdd = par_ref[PAR_VDD]
+    vpre = par_ref[PAR_VPRE]
+    active = par_ref[PAR_ACTIVE] > 0.5
+    role = (par_ref[PAR_ROLE] if par_ref.shape[0] > PAR_ROLE
             else jnp.zeros_like(thr_rel))   # static: role column presence
     is_rep = jnp.abs(role - 1.0) < 0.5
     is_main = role > 1.5
@@ -143,13 +163,14 @@ def _row_cycle_kernel(c_ref, g_ref, gcr_ref, gcp_ref, v0_ref, par_ref,
             x[i] = dp[i] - cp[i] * x[i + 1]
         v_next = tuple(jnp.where(done, v[i], x[i]) for i in range(n))
 
-        # threshold crossings on the fresh state, as int32 columns.  A main
-        # row's ACT crossing is the crossing of the replica at row-1:
-        # [replica, main] pairs are even-aligned inside a block and run ACT
-        # in lockstep, so row 0 is a replica and the wrapped value is unused.
+        # threshold crossings on the fresh state, as int32 tiles.  A main
+        # row's ACT crossing is the crossing of the replica at row-1, the
+        # lane before it: [replica, main] pairs are even-aligned, so a pair
+        # never straddles two sublanes, lane 0 of every sublane is a replica
+        # and the wrapped value is unused.  Pairs run ACT in lockstep.
         dv = v_next[0] - vpre
         cross_own = (dv >= thr_rel).astype(jnp.int32)
-        cross_prev = pltpu.roll(cross_own, 1, 0)
+        cross_prev = pltpu.roll(cross_own, 1, 1)
         cross_act = jnp.where(is_main, cross_prev, cross_own)
         cross_res = (v_next[n - 1] >= RESTORE_FRAC * vdd).astype(jnp.int32)
         dev = jnp.abs(v_next[0] - vpre)
@@ -179,16 +200,16 @@ def _row_cycle_kernel(c_ref, g_ref, gcr_ref, gcp_ref, v0_ref, par_ref,
         tin = jnp.where(advance, 0, jnp.where(done, tin, tin1))
         return t + 1, phase, tin, v_next, evt
 
-    zero = jnp.zeros((b, 1), jnp.float32)
+    zero = jnp.zeros(tile, jnp.float32)
     state = (jnp.int32(0), jnp.where(active, 0, 3).astype(jnp.int32),
-             jnp.zeros((b, 1), jnp.int32),
-             tuple(col(v0_ref, i) for i in range(n)), (zero,) * N_EVENTS)
+             jnp.zeros(tile, jnp.int32),
+             tuple(v0_ref[i] for i in range(n)), (zero,) * N_EVENTS)
     t_fin, _, _, v_fin, evt_fin = jax.lax.while_loop(cond, body, state)
-    steps_ref[...] = jnp.full((b, 1), t_fin, jnp.int32)
+    steps_ref[...] = jnp.full(tile, t_fin, jnp.int32)
     for k in range(N_EVENTS):
-        evt_ref[:, k:k + 1] = evt_fin[k]
+        evt_ref[k] = evt_fin[k]
     for i in range(n):
-        vend_ref[:, i:i + 1] = v_fin[i]
+        vend_ref[i] = v_fin[i]
 
 
 def row_cycle_fused_pallas(c: jnp.ndarray, g_branch: jnp.ndarray,
@@ -199,37 +220,48 @@ def row_cycle_fused_pallas(c: jnp.ndarray, g_branch: jnp.ndarray,
                            interpret: bool = True):
     """Pallas-backed equivalent of `ref.row_cycle_fused_ref`.
 
-    Returns a `RowCycleOut`: (events, v_end) with shapes ((B, 4), (B, N)),
-    and `block_steps` (ceil(B / b_blk),) int32, each block's trip count.
+    Takes (B, w) operands and returns a `RowCycleOut`: (events, v_end)
+    with shapes ((B, 4), (B, N)), and `block_steps` (ceil(B / rows),)
+    int32, each block's trip count, for blocks of `block_rows(B, b_blk)`
+    rows.  `b_blk` is a multiple of 128; only tests set it, to force
+    several blocks on a small batch.
     """
+    if b_blk <= 0 or b_blk % LANES:
+        raise ValueError(f"b_blk={b_blk} must be a positive multiple of "
+                         f"{LANES}")
     b, n = c.shape
-    b_blk = min(b_blk, b)
-    n_blocks = pl.cdiv(b, b_blk)
+    blk = block_rows(b, b_blk)
+    n_blocks = pl.cdiv(b, blk)
+    rows = n_blocks * blk
 
-    pad = n_blocks * b_blk - b
-    if pad:
-        padf = lambda x, v: jnp.pad(x, ((0, pad), (0, 0)), constant_values=v)
-        c, g_branch, gc_res, gc_pre, v0 = (
-            padf(x, 1.0) for x in (c, g_branch, gc_res, gc_pre, v0))
-        # padded rows get active=0 -> they start DONE and never step
-        params = padf(params, 0.0)
+    def tiles(x, fill):
+        """(B, w) -> (w, rows/128, 128), padded with `fill`."""
+        x = jnp.pad(x.T, ((0, 0), (0, rows - b)), constant_values=fill)
+        return x.reshape(x.shape[0], rows // LANES, LANES)
+
+    # padded rows get active=0 -> they start DONE and never step
+    c, g_branch, gc_res, gc_pre, v0 = (
+        tiles(x, 1.0) for x in (c, g_branch, gc_res, gc_pre, v0))
+    params = tiles(params, 0.0)
 
     kernel = functools.partial(_row_cycle_kernel, n_act=n_act, n_res=n_res,
                                n_pre=n_pre, dt=dt)
-    bspec = lambda w: pl.BlockSpec((b_blk, w), lambda i: (i, 0))
+    s_blk = blk // LANES
+    bspec = lambda w: pl.BlockSpec((w, s_blk, LANES), lambda i: (0, i, 0))
+    tshape = lambda w, dtype: jax.ShapeDtypeStruct((w, rows // LANES, LANES),
+                                                   dtype)
     events, v_end, steps = pl.pallas_call(
         kernel,
         grid=(n_blocks,),
         in_specs=[bspec(n), bspec(n - 1), bspec(n), bspec(n), bspec(n),
-                  bspec(params.shape[1])],  # (B, 5) legacy or (B, 6)
-        out_specs=[bspec(N_EVENTS), bspec(n), bspec(1)],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_blocks * b_blk, N_EVENTS), jnp.float32),
-            jax.ShapeDtypeStruct((n_blocks * b_blk, n), c.dtype),
-            jax.ShapeDtypeStruct((n_blocks * b_blk, 1), jnp.int32),
-        ],
+                  bspec(params.shape[0])],  # (B, 5) legacy or (B, 6)
+        out_specs=[bspec(N_EVENTS), bspec(n),
+                   pl.BlockSpec((s_blk, LANES), lambda i: (i, 0))],
+        out_shape=[tshape(N_EVENTS, jnp.float32), tshape(n, c.dtype),
+                   jax.ShapeDtypeStruct((rows // LANES, LANES), jnp.int32)],
         interpret=interpret,
         name="row_cycle_fused",
     )(c, g_branch, gc_res, gc_pre, v0, params)
-    return RowCycleOut(events[:b], v_end[:b],
-                       steps.reshape(n_blocks, b_blk)[:, 0])
+    untile = lambda x: x.reshape(x.shape[0], rows).T[:b]
+    return RowCycleOut(untile(events), untile(v_end),
+                       steps.reshape(n_blocks, blk)[:, 0])

@@ -128,11 +128,44 @@ class TestRowCycleFusedKernel:
         args = random_row_cycle_inputs(rng, b, n)
         self.check(args, n_act, n_res, n_pre)
 
-    def test_padded_batch_tail(self, rng):
-        """B=150 with b_blk=64 exercises a multi-block grid with a padded
-        last block; inactive padding rows must not perturb live points."""
-        args = random_row_cycle_inputs(rng, 150, 6)
-        self.check(args, 12, 10, 8, b_blk=64)
+    @pytest.mark.parametrize("b,b_blk", [(150, 128), (2048 + 150, 1024)])
+    def test_padded_batch_tail(self, rng, b, b_blk):
+        """A multi-block grid with a padded last block: 150 rows at 128-row
+        blocks, and a 2,048-row chunk plus 150 at full (8, 128) blocks of
+        1,024 rows; inactive padding rows must not perturb live points."""
+        args = random_row_cycle_inputs(rng, b, 6)
+        self.check(args, 12, 10, 8, b_blk=b_blk)
+
+    def test_replica_pairs_at_lane_and_sublane_edges(self, rng):
+        """[replica, main] pairs at lanes 126/127 and at lanes 0/1 of the
+        next sublane: each main row takes its ACT crossing from the replica
+        one lane before it, never from a neighbouring pair."""
+        b = 384
+        args = list(random_row_cycle_inputs(rng, b, 6))
+        params = np.array(args[5])
+        role = np.zeros(b)
+        pairs = (126, 128, 254, 256)
+        for r in pairs:
+            role[r], role[r + 1] = 1.0, 2.0
+        # one WL ramp, replica thresholds apart: a borrowed crossing shows
+        for k, r in enumerate(pairs):
+            params[r:r + 2, 0] = 1.0
+            params[r, 1] = 0.004 + 0.009 * k
+        args[5] = jnp.asarray(np.column_stack([params, role]).astype(np.float32))
+        self.check(args, 60, 15, 10)
+        evt, _ = row_cycle_fused_pallas(*args, self.DT, 60, 15, 10,
+                                        interpret=True)
+        t_dev = np.asarray(evt)[:, 0]
+        fire = [t_dev[r] for r in pairs]
+        assert np.isfinite(fire).all() and len(set(fire)) == len(pairs)
+        np.testing.assert_array_equal(t_dev[[r + 1 for r in pairs]], fire)
+        # a replica is ACT-only: no RESTORE or PRE event
+        np.testing.assert_array_equal(np.asarray(evt)[list(pairs), 2:], 0.0)
+
+    def test_block_rows_must_fill_whole_lanes(self, rng):
+        args = random_row_cycle_inputs(rng, 8, 6)
+        with pytest.raises(ValueError, match="b_blk=64"):
+            row_cycle_fused_pallas(*args, self.DT, 4, 4, 4, b_blk=64)
 
     def test_inactive_points_never_step(self, rng):
         """active=0 rows start DONE: zero event times, untouched state."""
@@ -152,14 +185,15 @@ class TestRowCycleFusedKernel:
         padding of the last block add nothing.  The oracle reports its own
         loop's count, with the whole batch as one block."""
         caps = (60, 60, 60)
-        args = list(random_row_cycle_inputs(rng, 200, 6))
+        b, blk = 400, 128
+        args = list(random_row_cycle_inputs(rng, b, 6))
         params = np.array(args[5])
-        params[64:128, 4] = 0.0                  # block 1: no live row
+        params[blk:2 * blk, 4] = 0.0             # block 1: no live row
         args[5] = jnp.asarray(params)
-        out = row_cycle_fused_pallas(*args, self.DT, *caps, b_blk=64,
+        out = row_cycle_fused_pallas(*args, self.DT, *caps, b_blk=blk,
                                      interpret=True)
         live = live_steps(out[0], params, caps, self.DT)
-        want = [live[lo:lo + 64].max() for lo in range(0, 200, 64)]
+        want = [live[lo:lo + blk].max() for lo in range(0, b, blk)]
         assert out.block_steps.dtype == jnp.int32
         np.testing.assert_array_equal(np.asarray(out.block_steps), want)
         assert want[1] == 0 and 0 < min(want[::2]) < max(want) < sum(caps)

@@ -126,10 +126,13 @@ def test_the_sweep_records_its_layers(fresh):
     assert all(r.root == root.id for r in recs.values())
 
 
-def test_engine_dispatch_counts_launches_rows_and_block_steps(fresh, monkeypatch):
+@pytest.mark.parametrize("backend", ["auto", "pallas"])
+def test_engine_dispatch_counts_launches_rows_and_block_steps(fresh, monkeypatch,
+                                                              backend):
     """Counters of one chunked dispatch against what the kernel seam saw:
     every launch, the rows given and padded, and the sum of the launches'
-    own block step counts."""
+    own block step counts, on the oracle (one block per launch) and on the
+    kernel (64 rows padded to one 128-lane block)."""
     plan = dse.plan_sweep(DesignSpace.paper_grid())
     b = int(plan.operands.c.shape[0])
     seen = []
@@ -140,12 +143,13 @@ def test_engine_dispatch_counts_launches_rows_and_block_steps(fresh, monkeypatch
         seen.append((args[0].shape[0], np.asarray(out.block_steps)))
         return out
     monkeypatch.setattr(ops, "row_cycle_fused", recording)
-    transient.simulate_row_cycle_lowered(plan.operands, b_chunk=64)
+    transient.simulate_row_cycle_lowered(plan.operands, backend, b_chunk=64)
     (rec,) = named("engine.dispatch")
     got = rec.counters
     assert got["launches"] == len(seen) == -(-b // 64) > 1
     assert got["rows"] == b
-    block = ops.row_cycle_block_rows(64)
+    block = ops.row_cycle_block_rows(64, backend)
+    assert all(len(s) == -(-n // block) for n, s in seen)
     assert got["rows_padded"] == sum(-(-n // block) * block for n, _ in seen)
     assert got["block_steps"] == sum(int(s.sum()) for _, s in seen)
     caps = transient.N_ACT_STEPS + transient.N_RESTORE_STEPS + transient.N_PRE_STEPS

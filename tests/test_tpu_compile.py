@@ -18,11 +18,13 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import transient
 from repro.kernels import ops
 from repro.kernels.rc_transient import rc_multistep_pallas
-from repro.kernels.row_cycle import (DEFAULT_B_BLK, N_PARAMS,
-                                     row_cycle_fused_pallas)
+from repro.kernels.row_cycle import N_PARAMS, row_cycle_fused_pallas
 from repro.kernels.strap_gather import strap_attend_pallas
 
 B, N = transient.DEFAULT_B_CHUNK, 6
+# a full chunk (two (8, 128) blocks) and one B_ALIGN batch (one (1, 128)
+# tile: the service and nominal-tRC path)
+ENGINE_ROWS = (B, transient.B_ALIGN)
 STEPS = (transient.N_ACT_STEPS, transient.N_RESTORE_STEPS,
          transient.N_PRE_STEPS)
 
@@ -73,11 +75,12 @@ def assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_row_cycle_kernel_compiles(one_chip):
+@pytest.mark.parametrize("b", ENGINE_ROWS)
+def test_row_cycle_kernel_compiles(one_chip, b):
     fn = jax.jit(functools.partial(
         row_cycle_fused_pallas, dt=transient.DT_NS, n_act=STEPS[0],
         n_res=STEPS[1], n_pre=STEPS[2], interpret=False))
-    assert_kernel(fn.lower(*engine_operands(one_chip)).compile())
+    assert_kernel(fn.lower(*engine_operands(one_chip, b=b)).compile())
 
 
 def test_auto_backend_dispatches_compiled_kernel(one_chip, on_tpu):
@@ -88,14 +91,18 @@ def test_auto_backend_dispatches_compiled_kernel(one_chip, on_tpu):
     assert_kernel(lowered.compile())
 
 
-def test_row_cycle_kernel_counts_block_steps_on_chip(one_chip, on_tpu):
+@pytest.mark.parametrize("b", ENGINE_ROWS)
+def test_row_cycle_kernel_counts_block_steps_on_chip(one_chip, on_tpu, b):
     """The kernel's third output on the chip: one int32 step count per
-    128-row block, from the custom call named `row_cycle_fused` (the op
-    the benchmark's trace readers match)."""
+    block of `ops.row_cycle_block_rows` rows, from the custom call named
+    `row_cycle_fused` (the op the benchmark's trace readers match)."""
     lowered = ops.row_cycle_fused.lower(
-        *engine_operands(one_chip), transient.DT_NS, *STEPS, backend="auto")
+        *engine_operands(one_chip, b=b), transient.DT_NS, *STEPS,
+        backend="auto")
     steps = lowered.out_info.block_steps
-    assert steps.shape == (B // DEFAULT_B_BLK,) and steps.dtype == jnp.int32
+    block = ops.row_cycle_block_rows(b)
+    assert block == min(1024, -(-b // 128) * 128)
+    assert steps.shape == (-(-b // block),) and steps.dtype == jnp.int32
     text = lowered.compile().as_text()
     kernels = [ln.split(" = ", 1)[0].strip() for ln in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in ln]
