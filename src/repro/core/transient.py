@@ -30,6 +30,7 @@ Two execution engines, same physics:
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import NamedTuple
 
@@ -275,10 +276,26 @@ def validate_b_chunk(b_chunk: int) -> int:
     return b_chunk
 
 
+_pad_batch = jax.jit(_pad_operands, static_argnums=1)
+
+
+@functools.partial(jax.jit, static_argnames=("rows",))
+def _split_chunks(padded, rows: int):
+    """Cut a padded batch into chunks of `rows`: one device program, with
+    every chunk of every operand its own output (a tuple of 6-tuples), so
+    no chunk is sliced on the host.  Keyed on the chunk count and rows,
+    not on the rows given."""
+    return tuple(tuple(x[lo:lo + rows] for x in padded)
+                 for lo in range(0, padded[0].shape[0], rows))
+
+
 @jax.jit
-def _sum_block_steps(parts):
-    """Total of every launch's per-block step counts: one device op."""
-    return jnp.concatenate(parts).sum()
+def _join_chunks(evts, v_ends, steps):
+    """Every launch's events and v_end, joined, and the total of their
+    per-block step counts (None where the seam gives none): one device
+    program after the last launch."""
+    total = None if steps is None else jnp.concatenate(steps).sum()
+    return jnp.concatenate(evts), jnp.concatenate(v_ends), total
 
 
 def _row_cycle_fused_chunked(operands, backend: str, b_chunk: int):
@@ -287,43 +304,48 @@ def _row_cycle_fused_chunked(operands, backend: str, b_chunk: int):
 
     Every call is padded with inactive design points to a B_ALIGN multiple
     no larger than `b_chunk` (which must itself be a B_ALIGN multiple), so
-    calls share compiled shapes and never exceed the caller's memory bound.
+    the kernel's programs are shared by every batch size and no launch
+    exceeds the caller's memory bound.  A batch of more than one chunk is
+    cut in one program (`_split_chunks`, keyed on the chunk count), each
+    chunk goes to the kernel in its own host-side call, and one program
+    joins the outputs (`_join_chunks`); a batch that is one whole chunk
+    goes to the kernel as it is.
 
     Runs as the `engine.dispatch` span (`repro.obs`), which counts the
     kernel `launches`, the `rows` given, the `rows_padded` after chunk and
-    block padding, and the kernel's own `block_steps`, summed on the
-    device after the last launch.  Nothing here reads a device value; the
-    runtime may still hold an enqueue while earlier launches run.
+    block padding, the `chunk_splits` (split programs), and the kernel's
+    own `block_steps`, summed on the device after the last launch.
+    Nothing here reads a device value; the runtime may still hold an
+    enqueue while earlier launches run.
     """
     b_chunk = validate_b_chunk(b_chunk)
     b = operands[0].shape[0]
+    rows = min(-(-b // B_ALIGN) * B_ALIGN, b_chunk)
+    pad = (-b) % rows
     with obs.span("engine.dispatch"):
-        if b <= b_chunk:
-            rows = min(-(-b // B_ALIGN) * B_ALIGN, b_chunk)
-            chunks = [_pad_operands(operands, rows - b)]
+        padded = tuple(_pad_batch(tuple(operands), pad) if pad else operands)
+        if b + pad == rows:
+            chunks = [padded]
         else:
-            rows = b_chunk
-            pad = (-b) % b_chunk
-            ops_padded = _pad_operands(operands, pad)
-            chunks = ([x[lo:lo + b_chunk] for x in ops_padded]
-                      for lo in range(0, b + pad, b_chunk))
+            chunks = _split_chunks(padded, rows)
+            obs.count("chunk_splits", 1)
         outs = [ops.row_cycle_fused(*chunk, DT_NS, N_ACT_STEPS,
                                     N_RESTORE_STEPS, N_PRE_STEPS,
                                     backend=backend)
                 for chunk in chunks]
-        if len(outs) == 1:
-            evt, v_end = outs[0][0][:b], outs[0][1][:b]
-        else:
-            evt = jnp.concatenate([o[0] for o in outs], axis=0)[:b]
-            v_end = jnp.concatenate([o[1] for o in outs], axis=0)[:b]
+        # a seam replaced by a plain (events, v_end) pair reports no count
+        steps = tuple(getattr(o, "block_steps", None) for o in outs)
+        evt, v_end, total = _join_chunks(tuple(o[0] for o in outs),
+                                         tuple(o[1] for o in outs),
+                                         None if None in steps else steps)
+        if pad:
+            evt, v_end = evt[:b], v_end[:b]
         block = ops.row_cycle_block_rows(rows, backend)
         obs.count("launches", len(outs))
         obs.count("rows", b)
         obs.count("rows_padded", len(outs) * -(-rows // block) * block)
-        # a seam replaced by a plain (events, v_end) pair reports no count
-        steps = [getattr(o, "block_steps", None) for o in outs]
-        if None not in steps:
-            obs.count("block_steps", _sum_block_steps(steps))
+        if total is not None:
+            obs.count("block_steps", total)
     return evt, v_end
 
 

@@ -187,6 +187,32 @@ def test_engine_dispatch_never_waits_for_the_device(fresh):
     assert named("engine.dispatch")[0].counters["launches"] > 1
 
 
+def test_engine_dispatch_host_programs_do_not_grow_with_chunks(fresh):
+    """The dispatch issues the same number of non-kernel programs at 5
+    and at 20 chunks (one pad, one split, one join of the outputs and
+    step counts, two cuts of the padded rows), and counts one split on a
+    multi-chunk batch and none on an unpadded single chunk."""
+    dispatch = lambda *x: transient._row_cycle_fused_chunked(x, "auto", 64)
+
+    def other_programs(n_chunks):
+        jaxpr = jax.make_jaxpr(dispatch)(*_abstract_operands(n_chunks * 64 - 3))
+        kernel = [e for e in jaxpr.jaxpr.eqns if e.params.get("name") == "row_cycle_fused"]
+        assert len(kernel) == n_chunks
+        return len(jaxpr.jaxpr.eqns) - len(kernel)
+    assert other_programs(5) == other_programs(20) <= 5
+    fresh.clear()                                # records holding tracers
+    plan = dse.plan_sweep(DesignSpace.paper_targets().with_mc(samples=40, key=0))
+    transient.simulate_row_cycle_lowered(plan.operands, b_chunk=64)
+    (multi,) = named("engine.dispatch")
+    assert multi.counters["launches"] > 1 and multi.counters["chunk_splits"] == 1
+    fresh.clear()
+    transient._row_cycle_fused_chunked(
+        [x[:64] for x in plan.operands[:6]], "auto", 64)
+    (single,) = named("engine.dispatch")
+    assert single.counters["launches"] == 1
+    assert single.counters.get("chunk_splits", 0) == 0
+
+
 def test_a_host_read_inside_the_dispatch_is_caught(fresh, monkeypatch):
     """The check above has teeth: a seam that reads an event on the host
     fails the same trace."""
